@@ -48,7 +48,7 @@ echo "== small-budget netsearch smoke =="
 # End-to-end network schedule search through the CLI shim: VGG16 at a
 # tiny budget must complete with the shape-as-operand executables and
 # print a schedule + baseline comparison.
-python -m repro.launch.netsearch --model vgg16 --quick --jax-cache-dir ''
+python -m repro.launch.netsearch --model vgg16 --quick --no-jax-cache
 
 echo "== declarative batch front door (--file) smoke =="
 # Serving-style mixed batch through repro.launch.query: 4 coalescible
@@ -62,7 +62,7 @@ echo "== declarative batch front door (--file) smoke =="
 python -m repro.launch.query --file examples/queries.json \
     --out benchmarks/out/api_batch_smoke.json \
     --trace benchmarks/out/api_batch_trace.json --metrics \
-    --cache-dir '' --jax-cache-dir ''
+    --cache-dir '' --no-jax-cache
 python - <<'EOF'
 import json
 d = json.load(open("benchmarks/out/api_batch_smoke.json"))
@@ -138,10 +138,10 @@ cat > "$RES_OUT/resilience_queries.json" <<'EOF'
 ]
 EOF
 python -m repro.launch.query --file "$RES_OUT/resilience_queries.json" \
-    --out "$RES_OUT/resilience_ref.json" --cache-dir '' --jax-cache-dir ''
+    --out "$RES_OUT/resilience_ref.json" --cache-dir '' --no-jax-cache
 if python -m repro.launch.query --file "$RES_OUT/resilience_queries.json" \
     --checkpoint-dir "$RES_CKPT" --faults kill@chunk:1 \
-    --cache-dir '' --jax-cache-dir '' 2> "$RES_OUT/resilience_kill.log"
+    --cache-dir '' --no-jax-cache 2> "$RES_OUT/resilience_kill.log"
 then
     echo "FAIL: injected kill@chunk:1 did not kill the sweep"
     exit 1
@@ -151,7 +151,7 @@ ls "$RES_CKPT"/sweep-batch-*.npz > /dev/null   # checkpoint survived
 python -m repro.launch.query --file "$RES_OUT/resilience_queries.json" \
     --checkpoint-dir "$RES_CKPT" \
     --out "$RES_OUT/resilience_resumed.json" --cache-dir '' \
-    --jax-cache-dir ''
+    --no-jax-cache
 python - <<'EOF'
 import json
 DET = ("kind", "name", "objective", "strategy", "best", "top_k",
@@ -209,7 +209,7 @@ EOF
 SERVE_DEADLINE=120
 python -m repro.launch.serve --port "$SERVE_PORT" \
     --deadline "$SERVE_DEADLINE" --checkpoint-dir "$SERVE_CKPT" \
-    --cache-dir '' --jax-cache-dir '' 2> "$SERVE_OUT/serve.log" &
+    --cache-dir '' --no-jax-cache 2> "$SERVE_OUT/serve.log" &
 SERVE_PID=$!
 python - "$SERVE_PORT" <<'EOF'
 import asyncio, sys, time
@@ -260,7 +260,7 @@ echo "== DSE serving kill@serve-drain restart drill =="
 python -m repro.launch.serve --port "$SERVE_PORT" \
     --checkpoint-dir "$SERVE_CKPT" --faults kill@serve-drain:0 \
     --flush-interval 30 --max-batch 64 --deadline 5 \
-    --cache-dir '' --jax-cache-dir '' 2>> "$SERVE_OUT/serve.log" &
+    --cache-dir '' --no-jax-cache 2>> "$SERVE_OUT/serve.log" &
 SERVE_PID=$!
 python - "$SERVE_PORT" "$SERVE_OUT/serve_queries.json" <<'EOF'
 import asyncio, json, sys
@@ -296,7 +296,7 @@ test -f "$SERVE_CKPT/serve-pending.json"
 # restart (no faults): recovery replays the persisted queue at start
 python -m repro.launch.serve --port "$SERVE_PORT" \
     --checkpoint-dir "$SERVE_CKPT" \
-    --cache-dir '' --jax-cache-dir '' 2>> "$SERVE_OUT/serve.log" &
+    --cache-dir '' --no-jax-cache 2>> "$SERVE_OUT/serve.log" &
 SERVE_PID=$!
 python - "$SERVE_PORT" <<'EOF'
 import asyncio, sys
@@ -322,7 +322,7 @@ if [ -f "$SERVE_CKPT/serve-pending.json" ]; then
     exit 1
 fi
 python -m repro.launch.query --file "$SERVE_OUT/serve_queries.json" \
-    --out "$SERVE_OUT/serve_oracle.json" --cache-dir '' --jax-cache-dir ''
+    --out "$SERVE_OUT/serve_oracle.json" --cache-dir '' --no-jax-cache
 python - <<'EOF'
 import json
 DET = ("kind", "name", "objective", "strategy", "best", "top_k",
@@ -352,7 +352,7 @@ rm -rf "$OBS_CKPT"
 python -m repro.launch.serve --port "$SERVE_PORT" \
     --checkpoint-dir "$OBS_CKPT" --max-queue 512 --deadline 120 \
     --trace "$OBS_OUT/obs_serve_trace.json" \
-    --cache-dir '' --jax-cache-dir '' 2> "$OBS_OUT/obs_serve.log" &
+    --cache-dir '' --no-jax-cache 2> "$OBS_OUT/obs_serve.log" &
 SERVE_PID=$!
 python - "$SERVE_PORT" <<'EOF'
 import asyncio, sys
@@ -446,7 +446,7 @@ rm -rf "$OBS_FLIGHT"
 mkdir -p "$OBS_FLIGHT"
 python -m repro.launch.serve --port "$SERVE_PORT" \
     --faults crash@serve-worker:0 --flight-dir "$OBS_FLIGHT" \
-    --deadline 60 --cache-dir '' --jax-cache-dir '' \
+    --deadline 60 --cache-dir '' --no-jax-cache \
     2>> "$OBS_OUT/obs_serve.log" &
 SERVE_PID=$!
 python - "$SERVE_PORT" "$SERVE_OUT/serve_queries.json" <<'EOF'

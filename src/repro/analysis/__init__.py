@@ -10,7 +10,7 @@ one CLI (``python -m repro.launch.lint``):
   and ``Query`` specs before any compile;
 * :mod:`repro.analysis.jaxpr_audit` — jaxpr-level invariants of every
   universal executable family (f64, callbacks, const-folded operands,
-  donation shrink, primitive budget).
+  reduce shrink, primitive budget).
 
 ``run_repo_lint`` is the cheap, jax-free pass (concurrency + shipped
 dataflow corpus); ``run_full`` adds the jaxpr audit.  Both return raw

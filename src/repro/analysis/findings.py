@@ -31,8 +31,8 @@ CODES: dict[str, str] = {
     "JAX-WEAKTYPE": "weak-typed output aval (recompile hazard)",
     "JAX-CONSTFOLD": "operand unused in the jaxpr — constant-folded "
                      "instead of vmapped (recompile hazard)",
-    "JAX-DONATION": "reduction tail does not shrink its inputs, so "
-                    "donated operand buffers cannot be consumed",
+    "JAX-SHRINK": "reduction tail does not shrink its inputs, so "
+                  "chunk results stop being O(k)",
     "JAX-PRIMBUDGET": "per-family jaxpr primitive count over budget",
     "JAX-TRACE": "family failed to trace at all",
     # concurrency linter (analysis/concurrency.py)

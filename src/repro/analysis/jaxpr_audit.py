@@ -24,10 +24,10 @@ invariants the engine's numerics and compile budget depend on:
     every operand array is actually *used* by the traced computation —
     an ignored operand means a value that should be vmapped got baked in
     as a static constant, i.e. a recompile per value;
-``JAX-DONATION``
+``JAX-SHRINK``
     the fused evaluate-and-reduce tail shrinks: total output bytes stay
-    under half the input bytes, so the donated operand buffer genuinely
-    covers the result and chunk memory stays O(block);
+    under half the input bytes, so each chunk ships back a fraction of
+    what it was sent;
 ``JAX-PRIMBUDGET``
     the traced primitive count per family stays under a checked-in
     budget (``PRIMITIVE_BUDGET``), the compile-time analog of the
@@ -169,7 +169,7 @@ def build_cases(n_devices: int = 1) -> list[FamilyCase]:
     from ..mapspace.space import build_space
     from ..mapspace.universal import encode_points, universal_specs
 
-    # large enough that the O(n) terms of the donation-shrink check
+    # large enough that the O(n) terms of the shrink check
     # dominate the O(k) top-k constants, as they do at real block sizes
     n = 256
     n -= n % n_devices
@@ -229,17 +229,17 @@ def build_cases(n_devices: int = 1) -> list[FamilyCase]:
 # ----------------------------------------------------------------------
 
 def _sub_jaxprs(params: dict):
-    import jax
+    from jax.extend import core
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for w in v:
-                if isinstance(w, jax.core.ClosedJaxpr):
+                if isinstance(w, core.ClosedJaxpr):
                     yield w.jaxpr
-                elif isinstance(w, jax.core.Jaxpr):
+                elif isinstance(w, core.Jaxpr):
                     yield w
 
 
@@ -327,6 +327,7 @@ def _audit_unwrapped(case: FamilyCase) -> list[Finding]:
     operand leaf is consumed.  Dict pytrees flatten in sorted-key order,
     so jaxpr.invars line up with sorted(ops)."""
     import jax
+    from jax.extend.core import Literal
     site = f"jaxpr::{case.name}"
     ops = case.unwrapped_ops or case.ops
     try:
@@ -340,12 +341,12 @@ def _audit_unwrapped(case: FamilyCase) -> list[Finding]:
     def mark(jaxpr):
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     used.add(id(v))
             for sub in _sub_jaxprs(eqn.params):
                 mark(sub)
         for v in jaxpr.outvars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 used.add(id(v))
 
     mark(closed.jaxpr)
@@ -375,17 +376,15 @@ def _aval_bytes(avals) -> int:
 
 
 def _audit_shrink(case: FamilyCase, closed, site: str) -> list[Finding]:
-    """JAX-DONATION: the fused reduce must shrink its input, otherwise
-    donating the operand buffer cannot cover the output and chunk memory
-    stops being O(block)."""
+    """JAX-SHRINK: the fused reduce must shrink its input, otherwise the
+    device-to-host copy per chunk grows with the chunk."""
     in_b = _aval_bytes(closed.in_avals)
     out_b = _aval_bytes(closed.out_avals)
     if out_b * 2 > in_b:
         return [Finding(
-            code="JAX-DONATION", site=site, analyzer="jaxpr",
+            code="JAX-SHRINK", site=site, analyzer="jaxpr",
             message=f"reduce tail returns {out_b} B for {in_b} B of "
-                    f"operands (> 1/2): the donated buffer no longer "
-                    f"covers the result")]
+                    f"operands (> 1/2)")]
     return []
 
 

@@ -8,7 +8,8 @@ wrappers over this path):
 
     from repro.api import Query, Workload, Hardware, SearchSpec, Session
 
-    s = Session(jax_cache_dir="~/.cache/repro/xla")
+    s = Session()   # compile cache: $JAX_COMPILATION_CACHE_DIR, else
+                    # <checkout>/.jax_cache
 
     # one layer, fixed hardware
     q = Query(Workload.of_layer(op), Hardware(num_pes=256, noc_bw=32.0),

@@ -4,7 +4,8 @@ A :class:`Session` owns everything that should outlive one query:
 
   * the on-disk RESULT cache (``cache_dir`` — ``mapspace.cache``,
     keyed by the full query fingerprint + engine schema version);
-  * the persistent XLA COMPILATION cache (``jax_cache_dir``);
+  * the persistent XLA COMPILATION cache (``jax_cache``; where it lives
+    is ``mapspace.cache.compilation_cache_dir``);
   * the in-process family registry: built network spaces and WARM
     universal executables keyed by (op-class, level-count), so repeated
     and concurrent queries never recompile what any earlier query
@@ -134,17 +135,16 @@ class _FamilyGroup:
 class Session:
     """See module docstring.  ``devices``/``block`` default every query
     that does not override them; ``cache_dir=None`` disables the result
-    cache (the in-process executable warmth still amortizes)."""
+    cache (the in-process executable warmth still amortizes);
+    ``jax_cache=False`` leaves JAX's persistent compilation cache off."""
 
     def __init__(self, *, cache_dir: str | None = None,
-                 jax_cache_dir: str | None = None,
+                 jax_cache: bool = True,
                  devices: int | None = None,
                  resilience: ResilienceConfig | None = None):
         import os
         expand = lambda p: os.path.expanduser(p) if p else p
         self.cache_dir = expand(cache_dir)
-        jax_cache_dir = expand(jax_cache_dir)
-        self.jax_cache_dir = jax_cache_dir
         self.devices = devices
         self.resilience = resilience or ResilienceConfig()
         if resilience is not None:
@@ -158,9 +158,10 @@ class Session:
         self.last_batch: dict[str, Any] | None = None
         self._queue: list[tuple[Query, PendingReport]] = []
         self._netspaces: dict[tuple, Any] = {}
-        if jax_cache_dir:
+        self.jax_cache_dir: str | None = None
+        if jax_cache:
             from ..mapspace.cache import enable_compilation_cache
-            enable_compilation_cache(jax_cache_dir)
+            self.jax_cache_dir = enable_compilation_cache()
 
     # ------------------------------------------------------------------
     # Single-query routing

@@ -13,6 +13,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import numpy as np
+
 from .cluster_analysis import Backend
 
 
@@ -46,6 +48,15 @@ class HWConfig:
     dram_bw: Any = 16.0          # off-chip elements/cycle (DDR-class)
     dram_energy_pj: float = 100.0  # per element off-chip transfer (28 nm)
     reconfig_latency: Any = 0.0  # fixed cycles per dataflow switch
+
+    def __post_init__(self):
+        # The universal engine carries noc_bw as a float32 operand.  The
+        # faithful engine takes the same float32 value, so that the floor
+        # in ceil_div sees one divisor in both: 1 + 2**-52 is 1.0 in f32,
+        # and left in f64 it would take one cycle off every transfer.
+        if isinstance(self.noc_bw, float):
+            object.__setattr__(self, "noc_bw",
+                               float(np.float32(self.noc_bw)))
 
     def replace(self, **kw) -> "HWConfig":
         return dataclasses.replace(self, **kw)

@@ -336,8 +336,7 @@ def _universal_eval_one(op: LayerOp, spec: UniversalSpec, hw_static: dict):
 # hardware tail folds the co-DSE's area/power/leakage accounting
 # (``core.dse.run_dse`` semantics) into the jit so a joint mapping x
 # hardware sweep needs no host post-processing either.  Chunks can stripe
-# across local devices via ``jax.pmap`` (``n_devices > 1``) and donate
-# their operand buffers on backends that support donation.
+# across local devices via ``jax.pmap`` (``n_devices > 1``).
 
 @dataclasses.dataclass(frozen=True)
 class HWTail:
@@ -392,11 +391,14 @@ def _reduce_tail(reduce: ReduceSpec, feats, ops):
         obj = -obj
     obj = jnp.where(jnp.isfinite(obj) & valid, obj, jnp.inf)
     k = min(reduce.k, feats.shape[0])
-    # lax.top_k is tie-stable (lower index first) — the cross-shard merge
-    # relies on that for 1-vs-N-device determinism
-    neg_top, top_idx = jax.lax.top_k(-obj, k)
+    # the cross-shard merge needs ties broken low-index-first for
+    # 1-vs-N-device determinism: sort on (value, index) rather than rely
+    # on how a backend's top_k orders equal values
+    idx = jnp.arange(obj.shape[0], dtype=jnp.int32)
+    top_v, top_idx = jax.lax.sort((obj, idx), num_keys=2)
+    top_v, top_idx = top_v[:k], top_idx[:k]
     out = {
-        "top_vals": -neg_top,
+        "top_vals": top_v,
         "top_idx": top_idx,
         "top_feats": feats[top_idx],
         "n_valid": jnp.sum(valid),
@@ -423,12 +425,6 @@ def _reduce_tail(reduce: ReduceSpec, feats, ops):
     return out
 
 
-def _donate() -> tuple:
-    """Operand-buffer donation, skipped on backends without support (CPU
-    would warn on every chunk)."""
-    return (0,) if jax.default_backend() != "cpu" else ()
-
-
 @functools.lru_cache(maxsize=256)
 def _build_reduced(op_key: str, spec: UniversalSpec, reduce: ReduceSpec,
                    multicast: bool, reduction: bool, latency: float,
@@ -444,8 +440,8 @@ def _build_reduced(op_key: str, spec: UniversalSpec, reduce: ReduceSpec,
         return _reduce_tail(reduce, feats, ops)
 
     if n_devices > 1:
-        return jax.pmap(chunk_fn, donate_argnums=_donate())
-    return jax.jit(chunk_fn, donate_argnums=_donate())
+        return jax.pmap(chunk_fn)
+    return jax.jit(chunk_fn)
 
 
 def universal_reduced_evaluator(op: LayerOp, spec: UniversalSpec,

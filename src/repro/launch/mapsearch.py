@@ -24,8 +24,8 @@ from repro.core import dnn_models as zoo
 from repro.core.dataflows import TABLE3, table3_for_layer
 from repro.core.model import analyze
 from repro.core.performance import HWConfig
-from repro.launch.query import (DEFAULT_CACHE, DEFAULT_JAX_CACHE, LOG,
-                                _fmt, add_obs_args, cli_errors,
+from repro.launch.query import (DEFAULT_CACHE, LOG, _fmt,
+                                add_jax_cache_arg, add_obs_args, cli_errors,
                                 obs_scope, print_batch_summary,
                                 print_layer_report,
                                 print_layer_codse_report,
@@ -142,8 +142,7 @@ def main(argv=None) -> None:
                          "sweep through the fused device pipeline")
     ap.add_argument("--cache-dir", default=DEFAULT_CACHE,
                     help="on-disk result cache ('' disables)")
-    ap.add_argument("--jax-cache-dir", default=DEFAULT_JAX_CACHE,
-                    help="persistent XLA compilation cache ('' disables)")
+    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
 

@@ -22,7 +22,7 @@ import argparse
 
 from repro.api import Hardware, Query, SearchSpec, Workload
 from repro.core import dnn_models as zoo
-from repro.launch.query import (DEFAULT_JAX_CACHE, _fmt, add_obs_args,
+from repro.launch.query import (_fmt, add_jax_cache_arg, add_obs_args,
                                 cli_errors, obs_scope,
                                 print_network_codse_report,
                                 print_network_report, session_from_args)
@@ -72,8 +72,7 @@ def main(argv=None) -> None:
                     help="tiny budget/frontier (smoke test)")
     ap.add_argument("--cache-dir", default="",
                     help="on-disk result cache ('' disables)")
-    ap.add_argument("--jax-cache-dir", default=DEFAULT_JAX_CACHE,
-                    help="persistent XLA compilation cache ('' disables)")
+    add_jax_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args(argv)
 

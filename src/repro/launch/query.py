@@ -42,7 +42,6 @@ from repro.resilience import ReproError, ResilienceConfig
 
 DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache",
                              "repro-mapspace")
-DEFAULT_JAX_CACHE = os.path.join(DEFAULT_CACHE, "xla")
 
 # THE launch-CLI logger: every diagnostic/progress line across the query
 # CLI and its shims routes through here (results still print to stdout);
@@ -271,6 +270,13 @@ def print_batch_summary(session: Session) -> None:
 # Query construction from flags
 # ----------------------------------------------------------------------
 
+def add_jax_cache_arg(ap) -> None:
+    ap.add_argument("--no-jax-cache", action="store_true",
+                    help="leave the persistent XLA compilation cache off "
+                         "(it lives in $JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache)")
+
+
 def session_from_args(args) -> Session:
     res = None
     ckpt_dir = getattr(args, "checkpoint_dir", None)
@@ -279,7 +285,7 @@ def session_from_args(args) -> Session:
         res = ResilienceConfig(ckpt_dir=ckpt_dir or None,
                                faults=faults or None)
     return Session(cache_dir=(args.cache_dir or None),
-                   jax_cache_dir=(args.jax_cache_dir or None),
+                   jax_cache=not args.no_jax_cache,
                    devices=args.devices, resilience=res)
 
 
@@ -341,9 +347,7 @@ def add_common_args(ap: argparse.ArgumentParser) -> None:
                     help="tiny budgets (smoke test)")
     ap.add_argument("--cache-dir", default=DEFAULT_CACHE,
                     help="on-disk result cache ('' disables)")
-    ap.add_argument("--jax-cache-dir", default=DEFAULT_JAX_CACHE,
-                    help="persistent XLA compilation cache "
-                         "('' disables)")
+    add_jax_cache_arg(ap)
     ap.add_argument("--checkpoint-dir", default=None, metavar="DIR",
                     help="sweep checkpoint directory: a killed run "
                          "re-launched with the same flags resumes "

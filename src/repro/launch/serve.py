@@ -25,7 +25,7 @@ import asyncio
 
 from repro.serve import DSEServer, ServeConfig
 
-from .query import (DEFAULT_CACHE, DEFAULT_JAX_CACHE, LOG, add_obs_args,
+from .query import (DEFAULT_CACHE, LOG, add_jax_cache_arg, add_obs_args,
                     cli_errors, obs_scope, session_from_args)
 
 
@@ -50,7 +50,7 @@ def add_serve_args(ap: argparse.ArgumentParser) -> None:
                     help="flush each request separately (oracle mode)")
     ap.add_argument("--devices", type=int, default=None)
     ap.add_argument("--cache-dir", default=DEFAULT_CACHE)
-    ap.add_argument("--jax-cache-dir", default=DEFAULT_JAX_CACHE)
+    add_jax_cache_arg(ap)
     ap.add_argument("--checkpoint-dir", default=None, metavar="DIR",
                     help="drain persistence + sweep checkpoints: a "
                          "killed drain resumes bit-identically here")

@@ -21,6 +21,7 @@ import hashlib
 import json
 import logging
 import os
+import pathlib
 import threading
 from typing import Any
 
@@ -44,7 +45,13 @@ CACHE_VERSION = 3
 # artifacts and the metrics snapshot schema exists alongside it.
 ENGINE_SCHEMA_VERSION = 2
 
-# Set once per process; repeated calls with the same directory are no-ops.
+# The compile cache's one fixed home when JAX_COMPILATION_CACHE_DIR is
+# unset: inside the checkout, never a temp, pid- or time-derived path
+# (JAX keys entries by directory, so a cache that moves never hits).
+CHECKOUT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
+
+# Set once per process; repeated calls are no-ops.
 _COMPILATION_CACHE_DIR: str | None = None
 
 # Guards the ``result_cache.entries``/``result_cache.bytes`` gauges AND
@@ -95,35 +102,33 @@ def cache_stats(cache_dir: str | None) -> tuple[int, int]:
     return entries, size
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so the
-    universal evaluator's one-off XLA compiles amortize across processes,
-    not just within one.  Returns True when the cache is active.
+def compilation_cache_dir() -> str:
+    """Where compiled executables persist: ``$JAX_COMPILATION_CACHE_DIR``
+    when it is set, else :data:`CHECKOUT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
 
-    Safe to call repeatedly; a different directory after the first call is
-    ignored (JAX initializes the cache lazily but only honours one
-    location per process)."""
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compilation_cache_dir`, so the universal evaluator's one-off
+    XLA compiles amortize across processes, not just within one.
+    Returns the directory.
+
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; only the checkout
+    default is ever written to JAX's config.  Safe to call repeatedly
+    (JAX honours one cache location per process)."""
     global _COMPILATION_CACHE_DIR
-    if not cache_dir:
-        return False
-    cache_dir = os.path.expanduser(cache_dir)
-    if _COMPILATION_CACHE_DIR is not None:
-        return True
-    try:
+    if _COMPILATION_CACHE_DIR is None:
         import jax
+        cache_dir = compilation_cache_dir()
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # persist even quick compiles: the universal executables are the
         # dominant cost and always worth keeping
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except (AttributeError, ValueError):
-            pass  # older jax: default threshold still persists big compiles
-    except Exception:
-        return False
-    _COMPILATION_CACHE_DIR = cache_dir
-    return True
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        _COMPILATION_CACHE_DIR = cache_dir
+    return _COMPILATION_CACHE_DIR
 
 
 def op_fingerprint(op: LayerOp) -> str:

@@ -349,7 +349,9 @@ def co_search_impl(op: LayerOp, objective: str = "edp",
             ckpt_dir, f"joint-{op.name}-{objective}-{joint_genes}-"
             f"{seed}-{cache_extra or 'local'}") if ckpt_dir else None
         joint = joint_sweep(op, sr.space, gm, cfg, objective=objective,
-                            block=joint_block, multicast=multicast,
+                            block=joint_block,
+                            n_devices=search_kwargs.get("devices"),
+                            multicast=multicast,
                             spatial_reduction=spatial_reduction,
                             ckpt=jc)
         n_compiles += joint.n_compiles

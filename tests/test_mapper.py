@@ -22,8 +22,8 @@ _SUB = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((8,), ("model",))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 
     def lower_gemm(spec_l, spec_r, spec_o):
         def f(a, b):

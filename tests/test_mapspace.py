@@ -197,6 +197,33 @@ def test_search_cache_roundtrip(tiny_conv, tiny_space, tmp_path):
     assert not d.cached
 
 
+def test_compilation_cache_dir_rule(monkeypatch):
+    import pathlib
+    from repro.mapspace import cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert cache.compilation_cache_dir() == str(root / ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/xla")
+    assert cache.compilation_cache_dir() == "/elsewhere/xla"
+
+
+def test_session_compiles_into_env_cache_dir(tmp_path):
+    import os
+    import subprocess
+    import sys
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.api import Session\n"
+            "s = Session()\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+            "print(s.jax_cache_dir)\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+    assert any(p.name.startswith("jit_") for p in tmp_path.iterdir())
+
+
 # ----------------------------------------------------------------------
 # Satellite regression: tile_variants symbolic handling
 # ----------------------------------------------------------------------

@@ -349,7 +349,7 @@ def test_cli_prints_one_line_error_and_exits_2(tmp_path, capsys):
         [{"workload": {"model": "vgg16"}, "search": {"strategy": "warp"}}]))
     with pytest.raises(SystemExit) as ei:
         qcli.main(["--file", str(bad), "--cache-dir", "",
-                   "--jax-cache-dir", ""])
+                   "--no-jax-cache"])
     assert ei.value.code == 2
     err = capsys.readouterr().err
     assert err.strip().splitlines()[-1].startswith("error: SpecError")
