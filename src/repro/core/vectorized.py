@@ -59,6 +59,21 @@ def stats_vector(op: LayerOp, df: Dataflow, hw: HWConfig) -> jnp.ndarray:
     return _features(analyze(op, df, hw, xp=xp))
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under a stable ``__name__``: ``jax.jit``/``jax.pmap`` name
+    the XLA module after it (``jit_<name>``), so a device trace tells the
+    executable families apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _family(kind: str, spec: "UniversalSpec") -> str:
+    """``universal_<kind>_l<levels>``, with ``_ext`` for the
+    layer-shape-as-operand (netspace) variant."""
+    return (f"universal_{kind}_l{spec.n_levels}"
+            + ("_ext" if spec.ext_operand else ""))
+
+
 @functools.lru_cache(maxsize=512)
 def _build_eval(op_key, df_key, multicast: bool, reduction: bool,
                 latency: float, macs_per_pe: int) -> Callable:
@@ -71,7 +86,7 @@ def _build_eval(op_key, df_key, multicast: bool, reduction: bool,
                       macs_per_pe=macs_per_pe)
         return stats_vector(op, df, hw)
 
-    return jax.jit(jax.vmap(eval_one))
+    return jax.jit(_named(jax.vmap(eval_one), "dse_grid"))
 
 
 # jit-cache registries keyed by object identity (LayerOp/Dataflow are
@@ -174,7 +189,7 @@ def _build_tile_eval(op_key, df_key, var_slots: tuple[int, ...],
         df = Dataflow(template.name, tuple(dirs))
         return stats_vector(op, df, hw)
 
-    return jax.jit(jax.vmap(eval_one))
+    return jax.jit(_named(jax.vmap(eval_one), "tile_features"))
 
 
 def batched_tile_evaluator(op: LayerOp, template: Dataflow,
@@ -439,6 +454,7 @@ def _build_reduced(op_key: str, spec: UniversalSpec, reduce: ReduceSpec,
             {k: v for k, v in ops.items() if k != "live"})
         return _reduce_tail(reduce, feats, ops)
 
+    _named(chunk_fn, _family("reduced", spec))
     if n_devices > 1:
         return jax.pmap(chunk_fn)
     return jax.jit(chunk_fn)
@@ -483,7 +499,8 @@ def _build_universal(op_key: str, spec: UniversalSpec, multicast: bool,
     op = _OP_REG[op_key]
     hw_static = dict(noc_latency=latency, multicast=multicast,
                      spatial_reduction=reduction, macs_per_pe=macs_per_pe)
-    return jax.jit(jax.vmap(_universal_eval_one(op, spec, hw_static)))
+    return jax.jit(_named(jax.vmap(_universal_eval_one(op, spec, hw_static)),
+                          _family("features", spec)))
 
 
 def universal_evaluator(op: LayerOp, spec: UniversalSpec, *,

@@ -197,15 +197,18 @@ def joint_sweep(op: LayerOp, space: MapSpace, genes: np.ndarray,
         check_cancel("design-chunk")
         fault_point("design-chunk")
         hi = min(lo + chunk_designs, n)
-        flat = np.arange(lo, hi, dtype=np.int64)
-        gi, hwi = flat // h, flat % h
-        # container span only (inner compile/device-pass spans carry the
-        # phase attribution) — names one (design x mapping) tile in a
-        # request's trace
+        # container span only (inner leaves carry the phase attribution)
+        # — names one (design x mapping) tile in a request's trace
         with obs.span("design-chunk", lo=int(lo), rows=int(hi - lo)):
+            # each design's mapping row and hardware point, gathered from
+            # its flat index
+            with obs.span("design-gather", rows=int(hi - lo)):
+                flat = np.arange(lo, hi, dtype=np.int64)
+                gi, hwi = flat // h, flat % h
+                g, g_pes, g_bws = genes[gi], pes[hwi], bws[hwi]
             res = evaluate_genes(
-                op, space, genes[gi], objective=col, maximize=maximize,
-                k=k, num_pes=pes[hwi], noc_bw=bws[hwi], block=block,
+                op, space, g, objective=col, maximize=maximize,
+                k=k, num_pes=g_pes, noc_bw=g_bws, block=block,
                 n_devices=n_devices, multicast=multicast,
                 spatial_reduction=spatial_reduction, return_vals=False,
                 pareto=True, hw_tail=tail)
@@ -249,15 +252,16 @@ def joint_sweep(op: LayerOp, space: MapSpace, genes: np.ndarray,
                 d["num_pes"], sram, d["noc_bw"]))
         return d
 
-    top_entries.sort(key=lambda e: (e[0], e[1]))
-    top = []
-    for v, row, feats in top_entries[:k]:
-        d = design(row, feats)
-        d["value"] = -v if maximize else v
-        top.append(d)
-    front = [dict(design(c["row"], None), energy_pj=c["energy_pj"],
-                  throughput=c["throughput"])
-             for c in pareto_front(front_cands)]
+    with obs.span("frontier-merge", op=op.name, rows=int(n)):
+        top_entries.sort(key=lambda e: (e[0], e[1]))
+        top = []
+        for v, row, feats in top_entries[:k]:
+            d = design(row, feats)
+            d["value"] = -v if maximize else v
+            top.append(d)
+        front = [dict(design(c["row"], None), energy_pj=c["energy_pj"],
+                      throughput=c["throughput"])
+                 for c in pareto_front(front_cands)]
     return JointSweepResult(
         n_designs=n, n_mappings=m, n_hw=h, n_valid=n_valid,
         objective=objective, top=top, pareto=front,
@@ -327,9 +331,12 @@ def co_search_impl(op: LayerOp, objective: str = "edp",
     sweeps: list[tuple[str, DSEResult]] = []
     n_compiles = 0
     for label, point in picked:
-        r, nc = _joint_sweep(op, sr.space, point, label, cfg, block=block,
-                             multicast=multicast,
-                             spatial_reduction=spatial_reduction)
+        # container span: its point-encode, h2d, device-pass and d2h
+        # leaves carry the phase attribution
+        with obs.span("hw-sweep", mapping=label):
+            r, nc = _joint_sweep(op, sr.space, point, label, cfg,
+                                 block=block, multicast=multicast,
+                                 spatial_reduction=spatial_reduction)
         n_compiles += nc
         sweeps.append((label, r))
     for name in include_table3:
@@ -356,25 +363,27 @@ def co_search_impl(op: LayerOp, objective: str = "edp",
                             ckpt=jc)
         n_compiles += joint.n_compiles
 
-    best: dict[str, dict[str, Any] | None] = {}
-    for obj in ("throughput", "energy", "edp"):
-        cands = [dict(r.best(obj), mapping=label)
-                 for label, r in sweeps if r.n_valid]
-        if joint is not None and joint.objective == obj and joint.top:
-            cands.append(dict(joint.top[0],
-                              mapping=f"joint:{joint.top[0]['point']}"))
-        if not cands:
-            best[obj] = None
-            continue
-        sign = (lambda p: -p["throughput"]) if obj == "throughput" else \
-            (lambda p: p["energy_pj"] if obj == "energy" else p["edp"])
-        best[obj] = min(cands, key=sign)
+    with obs.span("frontier-merge", op=op.name, sweeps=len(sweeps)):
+        best: dict[str, dict[str, Any] | None] = {}
+        for obj in ("throughput", "energy", "edp"):
+            cands = [dict(r.best(obj), mapping=label)
+                     for label, r in sweeps if r.n_valid]
+            if joint is not None and joint.objective == obj and joint.top:
+                cands.append(dict(joint.top[0],
+                                  mapping=f"joint:{joint.top[0]['point']}"))
+            if not cands:
+                best[obj] = None
+                continue
+            sign = (lambda p: -p["throughput"]) if obj == "throughput" \
+                else (lambda p: p["energy_pj"] if obj == "energy"
+                      else p["edp"])
+            best[obj] = min(cands, key=sign)
 
-    pareto = merged_pareto(sweeps)
-    if joint is not None and joint.pareto:
-        pareto = pareto_front(
-            pareto + [dict(p, mapping=f"joint:{p['point']}")
-                      for p in joint.pareto])
+        pareto = merged_pareto(sweeps)
+        if joint is not None and joint.pareto:
+            pareto = pareto_front(
+                pareto + [dict(p, mapping=f"joint:{p['point']}")
+                          for p in joint.pareto])
 
     return CoDSEResult(
         search=sr,

@@ -225,24 +225,29 @@ def evaluate_encoded(op: LayerOp, spec: UniversalSpec,
                      ) -> tuple[np.ndarray, UniversalRun]:
     """Run one operand batch through the universal executable with fixed
     block padding (so each (spec, block) compiles exactly once per
-    process); returns ``(features[n, F], run_stats)``."""
+    process); returns ``(features[n, F], run_stats)``.  Each block is an
+    ``h2d`` span (the operand copy), a ``device-pass`` (call and blocked
+    wait) and a ``d2h`` (the feature copy back)."""
     f = universal_evaluator(op, spec, multicast=multicast,
                             spatial_reduction=spatial_reduction)
     n = len(ops["pes"])
     feats = np.empty((n, len(FEATURES)), np.float32)
     run = UniversalRun(n_rows=n)
     wk = _warm_key(op, spec, multicast, spatial_reduction, block)
+    fam = family_label(op, spec)
     for lo in range(0, n, block):
         hi = min(lo + block, n)
         pad = block - (hi - lo)
-        batch = {}
+        chunks = {}
         for k, v in ops.items():
             chunk = v[lo:hi]
             if pad:
                 chunk = np.concatenate(
                     [chunk, np.repeat(v[lo:lo + 1], pad, 0)])
-            batch[k] = jnp.asarray(chunk)
-        fam = family_label(op, spec)
+            chunks[k] = chunk
+        with obs.span("h2d", family=fam, rows=block,
+                      bytes=sum(v.nbytes for v in chunks.values())):
+            batch = {k: jnp.asarray(v) for k, v in chunks.items()}
         if not is_warm(wk):
             # first call at this shape: jit compile — re-run timed so every
             # batch contributes a steady-rate sample
@@ -253,12 +258,12 @@ def evaluate_encoded(op: LayerOp, spec: UniversalSpec,
             if warm_once(wk, family=fam, seconds=dt):
                 run.compile_s += dt
                 run.n_compiles += 1
-        else:
-            obs.metrics().inc("universal.warm_hits", family=fam)
+        t0 = time.perf_counter()
         with obs.span("device-pass", family=fam, rows=hi - lo):
-            t0 = time.perf_counter()
-            out = np.asarray(f(batch))
-            run.eval_s += time.perf_counter() - t0
+            out = jax.block_until_ready(f(batch))
+        with obs.span("d2h", family=fam, rows=hi - lo, bytes=out.nbytes):
+            out = np.asarray(out)
+        run.eval_s += time.perf_counter() - t0
         feats[lo:hi] = out[:hi - lo]
     return feats, run
 
@@ -405,6 +410,14 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
     the executable — each chunk returns k winner rows plus a small
     frontier slice instead of the (n, F) feature matrix.
 
+    One chunk is, on the host and as ``obs`` leaf spans in order: the
+    numpy ``encode`` of its operands (padded to ``n_devices * block``
+    rows), their ``h2d`` copy (``bytes`` = the operands' ``nbytes``), the
+    async ``dispatch``, later the blocked ``device-pass`` wait, the
+    ``d2h`` copy of the reduced outputs (``bytes`` likewise) and the
+    ``topk-merge`` into the running accumulators.  The pass ends with one
+    ``frontier-merge``: the top-k sort and the exact Pareto refinement.
+
     ``objective`` is a FEATURES column name; ``num_pes``/``noc_bw`` may be
     scalars or per-row arrays (joint mapping x hardware rows); ``hw_tail``
     folds run_dse-style area/power/leakage accounting into the jit.
@@ -443,16 +456,15 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
     cand_t: list[np.ndarray] = []
 
     def collect(sub: np.ndarray, m: int, out: dict) -> None:
-        met = obs.metrics()
-        # the blocked wait for (and host copy of) this chunk's reduced
-        # device results — the host-visible tail of the device pass
+        # the blocked wait for this chunk's reduced device results (the
+        # host-visible tail of the device pass), then their copy back
+        t0 = time.perf_counter()
         with obs.span("device-pass", op=op.name, rows=m, devices=nd):
-            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+        with obs.span("d2h", op=op.name, rows=m,
+                      bytes=sum(v.nbytes for v in out.values())):
             host = {kk: np.asarray(v) for kk, v in out.items()}
-            dt = time.perf_counter() - t0
-        run.eval_s += dt
-        met.observe("gene.collect_wait_s", dt)
-        met.inc("gene.merge_bytes", sum(v.nbytes for v in host.values()))
+        run.eval_s += time.perf_counter() - t0
         chunk_rows = nd * block
         with obs.span("topk-merge", op=op.name, rows=m):
             if return_vals:
@@ -586,9 +598,9 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
                         chunk_rows), reduce, nd)
         pending: collections.deque = collections.deque()
 
-        def make_chunk(sub, m, in_flight):
+        def make_chunk(sub, m):
+            t0 = time.perf_counter()
             with obs.span("encode", family=fam_label, rows=m):
-                t0 = time.perf_counter()
                 batch = encode_genes(op, space, genes[sub], spec,
                                      num_pes=pes[sub], noc_bw=bw[sub])
                 pad = chunk_rows - m
@@ -599,14 +611,10 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
                 if nd > 1:
                     batch = {kk: v.reshape((nd, block) + v.shape[1:])
                              for kk, v in batch.items()}
+            with obs.span("h2d", family=fam_label, rows=m,
+                          bytes=sum(v.nbytes for v in batch.values())):
                 jbatch = {kk: jnp.asarray(v) for kk, v in batch.items()}
-                t_enc = time.perf_counter() - t0
-                run.encode_s += t_enc
-            if in_flight:
-                # double-buffer overlap, measured not guessed: host
-                # encode time spent while >= 1 chunk was in flight
-                met.inc("gene.overlap_encode_s", t_enc)
-            met.observe("gene.chunk_occupancy", m / chunk_rows)
+            run.encode_s += time.perf_counter() - t0
             return jbatch
 
         def dispatch(jbatch, m):
@@ -623,13 +631,9 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
                     run.compile_s += dt
                     run.n_compiles += 1
             else:
-                met.inc("universal.warm_hits", family=fam_label)
                 with obs.span("dispatch", family=fam_label, rows=m,
                               devices=nd):
-                    t0 = time.perf_counter()
                     out = f(jbatch)    # async dispatch
-                    met.observe("gene.dispatch_s",
-                                time.perf_counter() - t0)
                 run.n_steady += m
             return out
 
@@ -645,8 +649,7 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
                 return
 
             def once():
-                safe_collect(sub, m, dispatch(make_chunk(sub, m, False),
-                                              m))
+                safe_collect(sub, m, dispatch(make_chunk(sub, m), m))
             run_attempts(once, policy=retry,
                          label=f"{fam_label} chunk", first_exc=exc)
 
@@ -672,7 +675,7 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
             sub = fam[lo:lo + chunk_rows]
             m = sub.size
             try:
-                out = dispatch(make_chunk(sub, m, bool(pending)), m)
+                out = dispatch(make_chunk(sub, m), m)
             except Exception as exc:  # noqa: BLE001 — recover classifies
                 # drain in dispatch order first so the chunk cursor stays
                 # contiguous, then recover this chunk synchronously
@@ -695,18 +698,19 @@ def evaluate_genes(op: LayerOp, space: MapSpace, genes: np.ndarray, *,
     if ckpt is not None:
         ckpt.clear()               # completed: the checkpoint is spent
 
-    top_entries.sort(key=lambda e: (e[0], e[1]))
-    top = [{"row": r, "value": v, "feats": fr}
-           for v, r, fr in top_entries[:k]]
-    front: list[dict] = []
-    if pareto and cand_rows:
-        rows = np.concatenate(cand_rows)
-        es = np.concatenate(cand_e)
-        ts = np.concatenate(cand_t)
-        by_row = np.argsort(rows, kind="stable")
-        front = pareto_front(
-            [{"row": int(rows[i]), "energy_pj": float(es[i]),
-              "throughput": float(ts[i])} for i in by_row])
+    with obs.span("frontier-merge", op=op.name, rows=n):
+        top_entries.sort(key=lambda e: (e[0], e[1]))
+        top = [{"row": r, "value": v, "feats": fr}
+               for v, r, fr in top_entries[:k]]
+        front: list[dict] = []
+        if pareto and cand_rows:
+            rows = np.concatenate(cand_rows)
+            es = np.concatenate(cand_e)
+            ts = np.concatenate(cand_t)
+            by_row = np.argsort(rows, kind="stable")
+            front = pareto_front(
+                [{"row": int(rows[i]), "energy_pj": float(es[i]),
+                  "throughput": float(ts[i])} for i in by_row])
     run.e2e_s = time.perf_counter() - t_start
     # blocked-wait time understates device time under overlap; wall minus
     # host work is the tighter lower bound of the two
@@ -730,18 +734,19 @@ def evaluate_points_universal(op: LayerOp, space: MapSpace,
     pes = np.broadcast_to(np.asarray(num_pes, np.float32),
                           (len(points),))
     bw = np.broadcast_to(np.asarray(noc_bw, np.float32), (len(points),))
-    lvl1_idx = [i for i, pt in enumerate(points)
-                if space.cluster_options[pt[2]] is None]
-    lvl2_idx = [i for i, pt in enumerate(points)
-                if space.cluster_options[pt[2]] is not None]
+    with obs.span("point-encode", op=op.name, rows=len(points)):
+        lvl1_idx = [i for i, pt in enumerate(points)
+                    if space.cluster_options[pt[2]] is None]
+        lvl2_idx = [i for i, pt in enumerate(points)
+                    if space.cluster_options[pt[2]] is not None]
+        fams = [(spec, idxs, encode_points(
+                    op, space, [points[i] for i in idxs], spec,
+                    num_pes=pes[idxs], noc_bw=bw[idxs]))
+                for spec, idxs in ((spec1, lvl1_idx), (spec2, lvl2_idx))
+                if idxs]
     feats = np.empty((len(points), len(FEATURES)), np.float32)
     run = UniversalRun(n_rows=len(points))
-    for spec, idxs in ((spec1, lvl1_idx), (spec2, lvl2_idx)):
-        if not idxs:
-            continue
-        assert spec is not None
-        ops = encode_points(op, space, [points[i] for i in idxs], spec,
-                            num_pes=pes[idxs], noc_bw=bw[idxs])
+    for spec, idxs, ops in fams:
         sub, r = evaluate_encoded(op, spec, ops, block=block,
                                   multicast=multicast,
                                   spatial_reduction=spatial_reduction)

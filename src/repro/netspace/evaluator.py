@@ -206,16 +206,15 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
         run.encode_s += rrun.encode_s
 
     def collect(sub: np.ndarray, m: int, out: dict) -> None:
-        # the blocked wait for (and host copy of) this chunk's reduced
-        # device results — the host-visible tail of the device pass
+        # the blocked wait for this chunk's reduced device results (the
+        # host-visible tail of the device pass), then their copy back
+        t0 = time.perf_counter()
         with obs.span("device-pass", op=cls.rep.name, rows=m, devices=nd):
-            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+        with obs.span("d2h", op=cls.rep.name, rows=m,
+                      bytes=sum(v.nbytes for v in out.values())):
             host = {kk: np.asarray(v) for kk, v in out.items()}
-            dt = time.perf_counter() - t0
-        run.eval_s += dt
-        met.observe("netspace.collect_wait_s", dt)
-        met.inc("netspace.merge_bytes",
-                sum(v.nbytes for v in host.values()))
+        run.eval_s += time.perf_counter() - t0
         chunk_rows = nd * block
         vals[sub] = host["vals"].reshape(chunk_rows)[:m]
         cols[sub] = host["cols"].reshape(chunk_rows, len(COLS))[:m]
@@ -238,9 +237,9 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
               spatial_reduction, nd, chunk_rows)
         pending: collections.deque = collections.deque()
 
-        def make_chunk(sub, m, in_flight):
+        def make_chunk(sub, m):
+            t0 = time.perf_counter()
             with obs.span("encode", family=fam_label, rows=m):
-                t0 = time.perf_counter()
                 batch = _encode_rows(ns, cls, uid[sub], genes[sub], spec,
                                      pes=pes[sub], bw=bw[sub])
                 pad = chunk_rows - m
@@ -251,14 +250,10 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
                 if nd > 1:
                     batch = {kk: v.reshape((nd, block) + v.shape[1:])
                              for kk, v in batch.items()}
+            with obs.span("h2d", family=fam_label, rows=m,
+                          bytes=sum(v.nbytes for v in batch.values())):
                 jbatch = {kk: jnp.asarray(v) for kk, v in batch.items()}
-                t_enc = time.perf_counter() - t0
-                run.encode_s += t_enc
-            if in_flight:
-                # double-buffer overlap, measured not guessed: host
-                # encode time spent while >= 1 chunk was in flight
-                met.inc("netspace.overlap_encode_s", t_enc)
-            met.observe("netspace.chunk_occupancy", m / chunk_rows)
+            run.encode_s += time.perf_counter() - t0
             return jbatch
 
         def dispatch(jbatch, m):
@@ -275,13 +270,9 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
                     run.compile_s += dt
                     run.n_compiles += 1
             else:
-                met.inc("universal.warm_hits", family=fam_label)
                 with obs.span("dispatch", family=fam_label, rows=m,
                               devices=nd):
-                    t0 = time.perf_counter()
                     out = f(jbatch)    # async dispatch
-                    met.observe("netspace.dispatch_s",
-                                time.perf_counter() - t0)
                 run.n_steady += m
             return out
 
@@ -297,7 +288,7 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
                 return
 
             def once():
-                collect(sub, m, dispatch(make_chunk(sub, m, False), m))
+                collect(sub, m, dispatch(make_chunk(sub, m), m))
             run_attempts(once, policy=retry,
                          label=f"{fam_label} chunk", first_exc=exc)
 
@@ -323,7 +314,7 @@ def evaluate_rows(ns: NetSpace, uid: np.ndarray, genes: np.ndarray, *,
             sub = fam[lo:lo + chunk_rows]
             m = sub.size
             try:
-                out = dispatch(make_chunk(sub, m, bool(pending)), m)
+                out = dispatch(make_chunk(sub, m), m)
             except Exception as exc:  # noqa: BLE001 — recover classifies
                 # drain in dispatch order first so the chunk cursor stays
                 # contiguous, then recover this chunk synchronously

@@ -45,24 +45,36 @@ _PHASES: contextvars.ContextVar["PhaseBreakdown | None"] = \
 
 # Span name -> timing phase.  Only LEAF spans are mapped (the phases
 # must be disjoint wall-time intervals so they can sum to wall latency);
-# container spans (``query``, ``run_many``, ``flush``, ``design-chunk``)
-# stay unmapped or they would double-count their children.
+# container spans (``query``, ``run_many``, ``flush``, ``design-chunk``,
+# ``hw-sweep``) stay unmapped or they would double-count their children.
+# Leaves of one chunk, in order: ``encode`` (numpy operands) or
+# ``point-encode`` (the per-point Python encoder), ``h2d`` (host->device
+# copy), ``dispatch``, ``device-pass`` (the blocked wait), ``d2h``
+# (device->host copy of the outputs), ``topk-merge``; a pass ends with
+# ``frontier-merge`` (top-k sort and Pareto refinement).  A joint-sweep
+# ``design-chunk`` starts with ``design-gather`` (its rows' genes and
+# hardware points); ``hw-sweep`` holds one per-mapping hardware sweep.
 PHASE_OF_SPAN = {
     "coalesce": "coalesce_wait",
     "encode": "encode",
+    "point-encode": "encode",
+    "design-gather": "encode",
+    "h2d": "transfer",
+    "d2h": "transfer",
     "compile": "compile",
     "dispatch": "device_pass",
     "device-pass": "device_pass",
     "warmup": "compile",
     "topk-merge": "merge",
+    "frontier-merge": "merge",
     "compose": "merge",
 }
 
 # Canonical phase order for the ``timing`` breakdown.  ``queue_wait`` is
 # server-side (enqueue -> flush start); ``other`` is the residual that
 # makes the phases sum to measured wall latency by construction.
-PHASE_NAMES = ("queue_wait", "coalesce_wait", "encode", "compile",
-               "device_pass", "merge", "other")
+PHASE_NAMES = ("queue_wait", "coalesce_wait", "encode", "transfer",
+               "compile", "device_pass", "merge", "other")
 
 
 def new_request_id() -> str:

@@ -2,10 +2,11 @@
 
 One process-wide :class:`Metrics` registry (``metrics()``) collects the
 quantities the engine already *computes* but never *kept*: compiles per
-(op-class, level-count) family, warm-executable and result-cache
-hit/miss, genes evaluated, chunk occupancy, per-device dispatch time,
-bytes shipped across the top-k merge.  Everything is thread-safe and
-cheap (a dict update under a lock, at chunk — not row — granularity).
+(op-class, level-count) family, result-cache hit/miss, genes evaluated,
+fallbacks taken, the serving queue.  Per-chunk times and bytes are not
+kept here: they are the ``obs`` spans' (``h2d``/``d2h`` carry
+``bytes``).  Everything is thread-safe and cheap (a dict update under a
+lock, never per row).
 
 ``snapshot()`` returns a plain JSON-serializable dict with its own
 schema version; ``Report.bench`` and the query CLI embed it in BENCH_*

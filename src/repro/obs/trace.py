@@ -3,7 +3,8 @@
 One process-wide :class:`Tracer` (enabled on demand) collects *complete*
 events (``"ph": "X"``) so a whole ``Session.run_many`` batch renders as a
 timeline in ``chrome://tracing`` / https://ui.perfetto.dev: coalesce →
-encode → device-pass chunks per device → top-k merge → DP compose.
+per chunk encode → h2d → dispatch → device-pass → d2h → top-k
+merge → frontier merge → DP compose.
 
 Design constraints, in order:
 
