@@ -36,10 +36,10 @@ invariants the engine's numerics and compile budget depend on:
     the family traces at all (a trace error is itself a finding, not a
     crash).
 
-The audit corpus mirrors what CI actually compiles: a small conv2d and
-a gemm, 1-level and 2-level specs, in plain / reduced / co-DSE /
-netspace(ext-operand) variants, at 1 and ``jax.local_device_count()``
-devices (the pmap path).
+The audit corpus mirrors what CI actually compiles: a small conv2d, a
+gemm and an MLA decode score (rows dim searched), 1-level and 2-level
+specs, in plain / reduced / co-DSE / netspace(ext-operand) variants, at
+1 and ``jax.local_device_count()`` devices (the pmap path).
 """
 from __future__ import annotations
 
@@ -51,16 +51,19 @@ import numpy as np
 from .findings import Finding
 
 # Traced-primitive budget per audit family, measured over every variant
-# in the corpus (max: conv:L1 3883, conv:L2 13299, gemm:L1 1201,
-# gemm:L2 2695) with ~50% headroom.  A budget miss means an engine
-# change materially grew the program XLA must optimize — raise the
-# budget consciously, in review, like the compile-seconds budget in
-# BENCH_mapspace.
+# in the corpus (max: conv:L1 3884, conv:L2 13300, gemm:L1 1487,
+# gemm:L2 3459, mla:L1 1487, mla:L2 3459; gemm's grew from 1201 and 2695
+# when its rows dim N became a searched axis) with headroom.  A budget
+# miss means an engine change materially grew the program XLA must
+# optimize — raise the budget consciously, in review, like the
+# compile-seconds budget in BENCH_mapspace.
 PRIMITIVE_BUDGET = {
     "audit-conv:L1": 5800,
     "audit-conv:L2": 20000,
     "audit-gemm:L1": 1800,
     "audit-gemm:L2": 4100,
+    "audit-mla:L1": 2200,
+    "audit-mla:L2": 5200,
 }
 # Fallback for families outside the checked-in corpus (custom audits).
 _DEFAULT_BUDGET = {"L1": 6000, "L2": 20000}
@@ -96,9 +99,10 @@ class FamilyCase:
 # ----------------------------------------------------------------------
 
 def _audit_ops():
-    from ..core.tensor_analysis import conv2d, gemm
+    from ..core.tensor_analysis import conv2d, gemm, mla_decode_score
     return [conv2d("audit-conv", k=8, c=6, y=10, x=10, r=3, s=3),
-            gemm("audit-gemm", m=32, n=64, k=64)]
+            gemm("audit-gemm", m=32, n=64, k=64),
+            mla_decode_score("audit-mla", heads=8, context=256, qk_dim=72)]
 
 
 def _points(space, *, cluster: bool, n: int) -> list[tuple]:

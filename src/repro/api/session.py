@@ -360,7 +360,8 @@ class Session:
         rep.name = op.name
         return rep
 
-    def _network_kwargs(self, query: Query) -> dict[str, Any]:
+    def _network_kwargs(self, query: Query, layers: Sequence[LayerOp]
+                        ) -> dict[str, Any]:
         sp = query.search
         hw = query.hardware
         if sp.strategy not in ("auto", "exhaustive", "random"):
@@ -378,7 +379,9 @@ class Session:
             multicast=sp.multicast,
             spatial_reduction=sp.spatial_reduction,
             budget_policy=sp.budget_policy,
-            build_kwargs={"cluster": sp.cluster})
+            build_kwargs={"cluster": sp.cluster,
+                          "fusible": zoo.fusible_boundaries(
+                              query.workload.model, layers)})
 
     def _net_name(self, query: Query, layers: Sequence[LayerOp]) -> str:
         return query.workload.model or f"{len(layers)} layers"
@@ -386,7 +389,8 @@ class Session:
     def _run_network(self, query: Query) -> Report:
         from ..netspace.search import search_network_impl
         layers = query.workload.resolve()
-        r = search_network_impl(layers, **self._network_kwargs(query))
+        r = search_network_impl(layers,
+                                **self._network_kwargs(query, layers))
         rep = Report.from_network(r, query)
         rep.name = self._net_name(query, layers)
         return rep
@@ -396,7 +400,7 @@ class Session:
         sp = query.search
         hw = query.hardware
         layers = query.workload.resolve()
-        kw = self._network_kwargs(query)
+        kw = self._network_kwargs(query, layers)
         for k in ("objective", "budget", "seed", "frontier_k"):
             kw.pop(k)
         co = co_search_network_impl(

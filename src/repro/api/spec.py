@@ -72,7 +72,8 @@ OP_BUILDERS = {
     "pointwise_conv": ta.pointwise_conv,
     "conv1d": ta.conv1d,
     "lstm_cell": ta.lstm_cell,
-    "attention_score": ta.attention_score,
+    "mla_decode_score": ta.mla_decode_score,
+    "mla_decode_av": ta.mla_decode_av,
 }
 
 

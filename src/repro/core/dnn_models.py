@@ -12,9 +12,11 @@ Each layer is tagged ``early`` or ``late`` by the paper's rule (footnote 2):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
-from .tensor_analysis import (LayerOp, conv2d, dwconv2d, fc, pointwise_conv,
+from .tensor_analysis import (LayerOp, conv2d, dwconv2d, fc, mla_decode_av,
+                              mla_decode_score, pointwise_conv,
                               transposed_conv2d)
 
 
@@ -202,6 +204,94 @@ def unet() -> list[LayerOp]:
     return layers
 
 
+# ----------------------------------------------------------------------
+# DeepSeek-V3 — one decode step of one dense and one MoE layer
+# ----------------------------------------------------------------------
+
+# Published widths (huggingface.co/deepseek-ai/DeepSeek-V3, config.json)
+# and the decode deployment the zoo entry stands for.
+DSV3 = dict(hidden=7168, heads=128, q_lora=1536, kv_lora=512, rope=64,
+            dense_ffn=18432, expert_ffn=2048, n_experts=256, top_k=8,
+            batch=32, context=65536, ep=32)
+
+
+def _dsv3_decode() -> tuple[list[LayerOp], set[tuple[str, str]]]:
+    """Layers of :func:`deepseek_v3_decode` in schedule order, with the
+    (producer, consumer) names of the boundaries that may fuse."""
+    c = DSV3
+    b, h, t, d = c["batch"], c["heads"], c["context"], c["hidden"]
+    qk = c["kv_lora"] + c["rope"]                 # absorbed score width
+    n_here = c["n_experts"] // c["ep"]            # experts on this chip
+    tok = b * c["ep"] * c["top_k"] // c["n_experts"]  # tokens per expert
+    layers: list[LayerOp] = []
+    fused: set[tuple[str, str]] = set()
+
+    def fuse(producer: LayerOp, consumer: LayerOp) -> None:
+        layers.extend((producer, consumer))
+        fused.add((producer.name, consumer.name))
+
+    def ffn(pre: str, n: int, width: int) -> None:
+        fuse(fc(f"{pre}-gate_up", n=n, k=2 * width, c=d),
+             fc(f"{pre}-down", n=n, k=d, c=width))
+
+    for li, kind in enumerate(("dense", "moe")):
+        pre = f"dsv3-l{li}"
+        fuse(fc(f"{pre}-q_a", n=b, k=c["q_lora"], c=d),
+             fc(f"{pre}-q_b", n=b, k=h * qk, c=c["q_lora"]))
+        layers.append(fc(f"{pre}-kv_a", n=b, k=qk, c=d))
+        for i in range(b):
+            fuse(mla_decode_score(f"{pre}-score{i}", heads=h, context=t,
+                                  qk_dim=qk),
+                 mla_decode_av(f"{pre}-av{i}", heads=h, context=t,
+                               latent=c["kv_lora"]))
+        layers.append(fc(f"{pre}-o", n=b, k=d, c=h * c["kv_lora"]))
+        if kind == "dense":
+            ffn(f"{pre}-ffn", b, c["dense_ffn"])
+            continue
+        layers.append(fc(f"{pre}-router", n=b, k=c["n_experts"], c=d))
+        ffn(f"{pre}-shared", b, c["expert_ffn"])
+        for e in range(n_here):
+            ffn(f"{pre}-expert{e}", tok, c["expert_ffn"])
+    return layers, fused
+
+
+def deepseek_v3_decode() -> list[LayerOp]:
+    """One decode step of DeepSeek-V3 (arXiv:2412.19437): one of its
+    three leading dense layers and one MoE layer, each with absorbed
+    latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, §2.1), at every
+    published width (:data:`DSV3`).
+
+    The step decodes ``batch`` = 32 sequences at ``context`` = 65,536
+    cached tokens, on one chip's share of 32-way expert parallelism: 8 of
+    the 256 routed experts, each seeing 32 × 32 × 8 / 256 = 32 tokens.
+    Per layer: ``q_a`` (7168 → 1536); absorbed ``q_b`` (1536 → 128 heads
+    × 576, W_UK folded in); ``kv_a`` (7168 → 512 latent + 64 rope); per
+    sequence the score (:func:`~.tensor_analysis.mla_decode_score`, the
+    heads sharing that sequence's cache) and AV
+    (:func:`~.tensor_analysis.mla_decode_av`); absorbed ``o`` (128 × 512
+    → 7168, W_UV folded in); then the dense FFN (gate and up 2 × 18432,
+    down 18432) or the router (256 logits), the shared expert and the 8
+    routed experts here (gate and up 2 × 2048, down 2048).
+
+    Fusible boundaries (:data:`FUSED_EDGES`): q_a → q_b, score_i →
+    AV_i, and every FFN's gate-up → down; every other boundary hands its
+    output to more than the next layer (the cache, the residual stream,
+    the router's dispatch) and is not.
+
+    Departures from the published model:
+
+    * the absorption is pre-multiplied (one ``q_b`` of 1536 × 73,728 and
+      one ``o`` of 65,536 × 7168), which holds larger weights than the
+      batched per-head products of practice;
+    * softmax, RMSNorms, RoPE, the SwiGLU product and residual adds are
+      data movement, as the zoo treats residual links; the fused
+      gate-up → down edge carries the gate-up output (2 × width per
+      token) across it;
+    * routing is balanced: each expert here sees exactly its share;
+    * the multi-token-prediction (MTP) module is left out."""
+    return _dsv3_decode()[0]
+
+
 MODELS = {
     "vgg16": vgg16,
     "alexnet": alexnet,
@@ -209,7 +299,29 @@ MODELS = {
     "resnext50": resnext50,
     "mobilenet_v2": mobilenet_v2,
     "unet": unet,
+    "deepseek_v3_decode": deepseek_v3_decode,
 }
+
+
+# Zoo networks that are not chains: the (producer, consumer) layer names
+# of the boundaries where the producer's output feeds only the consumer,
+# the only ones that may fuse.  Every other network is a chain.
+FUSED_EDGES = {
+    "deepseek_v3_decode": functools.cache(lambda: _dsv3_decode()[1]),
+}
+
+
+def fusible_boundaries(model: str | None, layers: Sequence[LayerOp]
+                       ) -> list[bool] | None:
+    """``netspace.build_netspace``'s ``fusible`` for ``layers`` of the zoo
+    network ``model`` (all of them or a selection in network order):
+    ``mask[i]`` is True where ``layers[i] → layers[i+1]`` is one of
+    :data:`FUSED_EDGES`.  None (every boundary fusible) for a chain, an
+    explicit layer list (``model`` None) or a single layer."""
+    if model not in FUSED_EDGES:
+        return None
+    edges = FUSED_EDGES[model]()
+    return [(a.name, b.name) in edges for a, b in zip(layers, layers[1:])]
 
 
 # Representative operators used in Fig. 11 (reuse / bandwidth study).

@@ -191,9 +191,11 @@ def classify_level(op: LayerOp, level: LevelSpec) -> dict[str, TensorReuse]:
 def reuse_opportunity_table(op: LayerOp) -> dict[tuple[str, str], dict]:
     """Programmatic regeneration of the paper's Table 1: for each (spatially
     mapped dim, innermost temporally mapped dim) pair, the coupling of each
-    tensor and the implied reuse opportunity."""
+    tensor and the implied reuse opportunity.  The batch/rows dim ``N``
+    takes part wherever its extent is above 1 (the heads of an attention
+    score, the tokens of a projection)."""
     table = {}
-    dims = [d for d in op.dims if op.dims[d] >= 1 and d != "N"]
+    dims = [d for d in op.dims if d != "N" or op.dims[d] > 1]
     red = op.reduction_dims()
     for sd in dims:
         for td in dims:

@@ -312,10 +312,30 @@ def lstm_cell(name: str, *, n: int = 1, hidden: int, inp: int) -> LayerOp:
     return fc(name, n=n, k=4 * hidden, c=inp + hidden)
 
 
-def attention_score(name: str, *, bh: int, q: int, kv: int,
-                    d: int) -> LayerOp:
-    """Q·K^T per (batch·head): used by the TPU mapper bridge."""
-    return fc(name, n=bh * q, k=kv, c=d)
+def mla_decode_score(name: str, *, heads: int, context: int,
+                     qk_dim: int) -> LayerOp:
+    """Absorbed latent-attention (MLA) decode score of ONE sequence
+    (DeepSeek-V2, arXiv:2405.04434, §2.1): ``O[h,t] = Σ_c Q[h,c]·KV[t,c]``
+    over the ``qk_dim``-wide latent (kv_lora_rank + rope dims).
+
+    As ``fc(n=heads, k=context, c=qk_dim)``: the latent cache plays the
+    filter, coupled to K (tokens) and C, and the ``heads`` rows share it —
+    the heads of one sequence and nothing else.  Two sequences share no
+    cache rows, so a decode batch of B sequences is B independent
+    instances of this op, never one op with ``n = B·heads``."""
+    return fc(name, n=heads, k=context, c=qk_dim)
+
+
+def mla_decode_av(name: str, *, heads: int, context: int,
+                  latent: int) -> LayerOp:
+    """Absorbed MLA decode attention-times-value of ONE sequence:
+    ``O[h,c] = Σ_t P[h,t]·KV[t,c]`` over the ``latent`` cache columns.
+
+    As ``fc(n=heads, k=latent, c=context)``: the cache is the filter again
+    (coupled to K = latent columns and C = tokens, the reduction), shared
+    by the ``heads`` rows of this sequence only; a batch is independent
+    instances, as for :func:`mla_decode_score`."""
+    return fc(name, n=heads, k=latent, c=context)
 
 
 def output_dims(op: LayerOp) -> dict[str, int]:

@@ -31,7 +31,7 @@ from .search import OBJECTIVES, SearchResult, search
 from .space import (MapSpace, genes_from_points, point_dataflow,
                     sample_genes)
 from .universal import (evaluate_genes, evaluate_points_universal,
-                        pareto_front)
+                        gene_families, pareto_front)
 
 
 @dataclasses.dataclass
@@ -175,6 +175,8 @@ def joint_sweep(op: LayerOp, space: MapSpace, genes: np.ndarray,
     n_compiles = 0
     compile_s = 0.0
     n_dev = 1
+    fam_of = gene_families(op, space, genes)     # per mapping row
+    met = obs.metrics()
 
     start_lo = 0
     ckpt_meta: dict | None = None
@@ -197,9 +199,18 @@ def joint_sweep(op: LayerOp, space: MapSpace, genes: np.ndarray,
         check_cancel("design-chunk")
         fault_point("design-chunk")
         hi = min(lo + chunk_designs, n)
+        # the chunk's designs per executable family: mapping rows
+        # lo // h .. (hi - 1) // h, each for its share of [lo, hi)
+        ms = np.arange(lo // h, (hi - 1) // h + 1)
+        per = np.minimum((ms + 1) * h, hi) - np.maximum(ms * h, lo)
+        fams = {f: int(per[fam_of[ms] == f].sum())
+                for f in dict.fromkeys(fam_of[ms].tolist())}
+        for f, c in fams.items():
+            met.inc("codse.designs", c, family=f)
         # container span only (inner leaves carry the phase attribution)
         # — names one (design x mapping) tile in a request's trace
-        with obs.span("design-chunk", lo=int(lo), rows=int(hi - lo)):
+        with obs.span("design-chunk", lo=int(lo), rows=int(hi - lo),
+                      family="+".join(fams)):
             # each design's mapping row and hardware point, gathered from
             # its flat index
             with obs.span("design-gather", rows=int(hi - lo)):
@@ -330,10 +341,13 @@ def co_search_impl(op: LayerOp, objective: str = "edp",
     cfg = cfg or DSEConfig()
     sweeps: list[tuple[str, DSEResult]] = []
     n_compiles = 0
+    n_hw = len(cfg.pe_range) * len(cfg.bw_range)
     for label, point in picked:
+        fam = str(gene_families(op, sr.space, [point])[0])
+        obs.metrics().inc("codse.designs", n_hw, family=fam)
         # container span: its point-encode, h2d, device-pass and d2h
         # leaves carry the phase attribution
-        with obs.span("hw-sweep", mapping=label):
+        with obs.span("hw-sweep", mapping=label, family=fam):
             r, nc = _joint_sweep(op, sr.space, point, label, cfg,
                                  block=block, multicast=multicast,
                                  spatial_reduction=spatial_reduction)

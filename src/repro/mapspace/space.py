@@ -173,7 +173,7 @@ def build_space(op: LayerOp, *,
     pinned = _pinned_dims(op)
     if dims is None:
         dims = [d for d in op.dims
-                if op.dims[d] > 1 and d not in pinned and d != "N"]
+                if op.dims[d] > 1 and d not in pinned]
     dims = list(dims)
     if not dims:
         raise MapSpaceError(f"{op.name}: no searchable dims")

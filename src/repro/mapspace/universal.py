@@ -109,7 +109,20 @@ def mark_warmed(op: LayerOp, spec, multicast: bool, reduction: bool,
 def family_label(op: LayerOp, spec) -> str:
     """Human-readable (op, level-count) family name for metrics/spans:
     ``conv1:L2`` = conv1's 2-level (clustered) executable family."""
-    return f"{op.name}:L{2 if getattr(spec, 'cluster', None) else 1}"
+    return _family(op, 2 if getattr(spec, "cluster", None) else 1)
+
+
+def _family(op: LayerOp, levels: int) -> str:
+    return f"{op.name}:L{levels}"
+
+
+def gene_families(op: LayerOp, space: MapSpace, genes: np.ndarray
+                  ) -> np.ndarray:
+    """Each gene row's executable family (:func:`family_label`): the
+    2-level family where its cluster option is set, else the 1-level."""
+    is2 = ~gene_tables(op, space).cluster_is_none[
+        np.asarray(genes, np.int64)[:, 2]]
+    return np.where(is2, _family(op, 2), _family(op, 1))
 
 
 def _cluster_candidate(copt: ClusterOption, op: LayerOp

@@ -231,7 +231,11 @@ def search_network_impl(model, objective: str = "edp", budget: int = 512,
         raise ValueError(f"budget_policy must be one of "
                          f"{BUDGET_POLICIES}")
     layers = _layers_of(model)
-    ns = netspace or build_netspace(layers, **(build_kwargs or {}))
+    # a zoo name brings its own fusible boundaries
+    fusible = zoo.fusible_boundaries(
+        model if isinstance(model, str) else None, layers)
+    ns = netspace or build_netspace(
+        layers, **{"fusible": fusible, **(build_kwargs or {})})
     if hw is None:
         hw = HWConfig(num_pes=num_pes, noc_bw=noc_bw, noc_latency=2.0)
     # a caller-supplied HWConfig IS the reference design: its hardware

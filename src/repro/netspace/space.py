@@ -163,11 +163,10 @@ def build_netspace(layers: Sequence[LayerOp], *,
         # get the single trivial candidate and ride along)
         dims = tuple(
             d for d in rep.dims
-            if d not in pinned and d != "N"
+            if d not in pinned
             and any(unique[u].dims[d] > 1 for u in members))
         if not dims:
-            dims = tuple(d for d in rep.dims
-                         if d not in pinned and d != "N")[:1]
+            dims = tuple(d for d in rep.dims if d not in pinned)[:1]
         mode = perm_mode
         if mode == "auto":
             mode = "all" if len(dims) <= 3 else "rotations"

@@ -54,6 +54,8 @@ _PHASES: contextvars.ContextVar["PhaseBreakdown | None"] = \
 # ``frontier-merge`` (top-k sort and Pareto refinement).  A joint-sweep
 # ``design-chunk`` starts with ``design-gather`` (its rows' genes and
 # hardware points); ``hw-sweep`` holds one per-mapping hardware sweep.
+# Both containers carry ``family=``: the executable families of their
+# designs, which the ``codse.designs`` counter also counts by.
 PHASE_OF_SPAN = {
     "coalesce": "coalesce_wait",
     "encode": "encode",

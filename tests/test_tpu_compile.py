@@ -94,11 +94,12 @@ def _reduce(kind: str) -> ReduceSpec:
     return reduce
 
 
-@pytest.mark.parametrize("levels,kind", [
-    (1, "search"), (2, "search"), (2, "codse"), (1, "netspace")])
-def test_conv13_family_compiles_for_v5e(one_chip, no_persistent_cache,
-                                        levels, kind):
-    op = _conv13()
+def _mla_score():
+    return next(op for op in zoo.deepseek_v3_decode()
+                if "score" in op.name)
+
+
+def _compile(one_chip, op, levels: int, kind: str) -> None:
     space = build_space(op)
     spec = universal_specs(op, space)[levels - 1]
     assert spec is not None
@@ -114,3 +115,21 @@ def test_conv13_family_compiles_for_v5e(one_chip, no_persistent_cache,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("levels,kind", [
+    (1, "search"), (2, "search"), (2, "codse"), (1, "netspace")])
+def test_conv13_family_compiles_for_v5e(one_chip, no_persistent_cache,
+                                        levels, kind):
+    _compile(one_chip, _conv13(), levels, kind)
+
+
+@pytest.mark.parametrize("levels,kind", [
+    (1, "codse"), (2, "codse"), (1, "netspace")])
+def test_mla_score_family_compiles_for_v5e(one_chip, no_persistent_cache,
+                                           levels, kind):
+    """DeepSeek-V3's decode score at its published width, its rows dim
+    (the 128 heads) a searched axis."""
+    op = _mla_score()
+    assert op.dims == {"N": 128, "K": 65536, "C": 576}
+    _compile(one_chip, op, levels, kind)
